@@ -514,11 +514,22 @@ def _run_worker(module: str, n_dev: int) -> dict:
     """Run one forced-host-device bench worker subprocess (the
     ``--xla_force_host_platform_device_count`` flag must land before
     jax initializes, and this process's jax is already up) and parse
-    its single-JSON-document stdout."""
+    its single-JSON-document stdout.
+
+    Only on the CPU backend: on an accelerator this process holds the
+    chip, and a child that needs a device would fail or hang."""
     import json
     import os
     import subprocess
 
+    import jax
+    if jax.default_backend() != "cpu":
+        raise RuntimeError(
+            f"{module} runs on forced host devices in a child process, "
+            f"but this process holds the {jax.default_backend()} backend; "
+            "a chip serves one process at a time.  Run this section with "
+            "JAX_PLATFORMS=cpu; the multi-chip path is "
+            "`python chip_smoke.py --chips 4`.")
     env = dict(os.environ)
     env["PYTHONPATH"] = ("src" + os.pathsep + env["PYTHONPATH"]
                          if env.get("PYTHONPATH") else "src")
@@ -981,6 +992,8 @@ def main() -> None:
                          "sharded,rebalance_live,restart,obs,ckpt,"
                          "kernels,roofline")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     only = set(args.only.split(",")) if args.only else None
     rows = []
     if only is None or any(o.startswith("fig") for o in only):

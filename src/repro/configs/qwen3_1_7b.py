@@ -1,5 +1,5 @@
 """qwen3-1.7b [dense]: 28L d_model=2048 16H (kv=8) d_ff=6144
-vocab=151936, qk_norm + GQA. [hf:Qwen/Qwen3-8B; hf]"""
+vocab=151936, qk_norm + GQA, tied embeddings. [hf:Qwen/Qwen3-1.7B; hf]"""
 from .base import ArchConfig
 
 QWEN3_1_7B = ArchConfig(
@@ -14,6 +14,7 @@ QWEN3_1_7B = ArchConfig(
     vocab=151936,
     qk_norm=True,
     rope_theta=1e6,
+    tie_embeddings=True,
     microbatches=2,
     attn_impl="blocked",
     sp_prefill=True,

@@ -28,6 +28,13 @@ def get_arch(name: str) -> ArchConfig:
     return ARCHS[name]
 
 
+def parse_arch(spec: str) -> ArchConfig:
+    """An arch by name, or its reduced config as ``tiny:<name>``."""
+    if spec.startswith("tiny:"):
+        return tiny(get_arch(spec[5:]))
+    return get_arch(spec)
+
+
 def cells():
     """All (arch, shape) dry-run cells, including documented skips."""
     out = []

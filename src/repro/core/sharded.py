@@ -63,7 +63,6 @@ from typing import NamedTuple, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from . import batched
@@ -243,17 +242,17 @@ def _build_fns(mesh, S: int, n_buckets: int, nb_max: int):
 
     sspec = _state_specs()
     ospec = ShardCommitStats(*([P(AXIS)] * 7))
-    # check_rep=False: the chain-walk while_loop has no replication rule
-    # in jax 0.4.37; every output here is explicitly sharded anyway.
-    update_fn = jax.jit(shard_map(
+    # check_vma=False: every output here is explicitly sharded, so there
+    # is no replication to check.
+    update_fn = jax.jit(jax.shard_map(
         update_local, mesh=mesh,
         in_specs=(sspec, P(AXIS), P(AXIS), P(AXIS), P(AXIS), P(None),
                   P(AXIS), P(AXIS)),
-        out_specs=(sspec, P(AXIS), ospec), check_rep=False))
-    lookup_fn = jax.jit(shard_map(
+        out_specs=(sspec, P(AXIS), ospec), check_vma=False))
+    lookup_fn = jax.jit(jax.shard_map(
         lookup_local, mesh=mesh,
         in_specs=(sspec, P(AXIS), P(AXIS), P(None), P(AXIS)),
-        out_specs=(P(AXIS), P(AXIS), P(AXIS)), check_rep=False))
+        out_specs=(P(AXIS), P(AXIS), P(AXIS)), check_vma=False))
     return update_fn, lookup_fn
 
 

@@ -28,7 +28,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..compat import COMPILER_PARAMS as _COMPILER_PARAMS
+
+def _column(row, eye):
+    """[1, Q] row -> [Q, 1] column without a transpose: keep the diagonal
+    of the row broadcast down the sublanes, then reduce across lanes."""
+    return jnp.sum(jnp.where(eye, row, 0.0), axis=1, keepdims=True)
 
 
 def _kernel(x_ref, dt_ref, dA_ref, b_ref, c_ref, y_ref, state_sc, *,
@@ -40,20 +44,25 @@ def _kernel(x_ref, dt_ref, dA_ref, b_ref, c_ref, y_ref, state_sc, *,
         state_sc[...] = jnp.zeros_like(state_sc)
 
     x = x_ref[0, 0].astype(jnp.float32)        # [Q, P]
-    dt = dt_ref[0, 0].astype(jnp.float32)      # [Q]
-    dA = dA_ref[0, 0].astype(jnp.float32)      # [Q]
+    dt = dt_ref[0, 0].astype(jnp.float32)      # [1, Q]
+    dA = dA_ref[0, 0].astype(jnp.float32)      # [1, Q]
     Bm = b_ref[0, 0].astype(jnp.float32)       # [Q, N]
     Cm = c_ref[0, 0].astype(jnp.float32)       # [Q, N]
 
-    cum = jnp.cumsum(dA)                       # [Q] inclusive
+    row = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    tri = row >= col
+    eye = row == col
+    # inclusive cumsum as a masked lane reduction, kept in both layouts
+    cum_c = jnp.sum(jnp.where(tri, dA, 0.0), axis=1, keepdims=True)  # [Q,1]
+    cum_r = jnp.sum(jnp.where(eye, cum_c, 0.0), axis=0,
+                    keepdims=True)                                   # [1,Q]
+    total = jnp.sum(dA, axis=1, keepdims=True)                       # [1,1]
     # intra-chunk: masked decay kernel L[i,j] = exp(cum_i - cum_j), j <= i
-    diff = cum[:, None] - cum[None, :]
-    tri = (jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
-           >= jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1))
-    L = jnp.where(tri, jnp.exp(diff), 0.0)     # [Q, Q]
+    L = jnp.where(tri, jnp.exp(cum_c - cum_r), 0.0)     # [Q, Q]
     cb = jax.lax.dot_general(Cm, Bm, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)
-    scores = cb * L * dt[None, :]              # [Q, Q]
+    scores = cb * L * dt                       # [Q, Q]
     y = jax.lax.dot_general(scores, x, (((1,), (0,)), ((), ())),
                             preferred_element_type=jnp.float32)
 
@@ -61,13 +70,13 @@ def _kernel(x_ref, dt_ref, dA_ref, b_ref, c_ref, y_ref, state_sc, *,
     state = state_sc[...]                      # [P, N]
     y_inter = jax.lax.dot_general(Cm, state, (((1,), (1,)), ((), ())),
                                   preferred_element_type=jnp.float32)
-    y = y + y_inter * jnp.exp(cum)[:, None]
+    y = y + y_inter * jnp.exp(cum_c)
 
     # state update: S <- exp(cum_last) * S + X^T diag(w) B,  w = dt*decay
-    w = (jnp.exp(cum[-1] - cum) * dt)[:, None]           # [Q,1]
+    w = jnp.exp(total - cum_c) * _column(dt, eye)        # [Q,1]
     s_local = jax.lax.dot_general(x * w, Bm, (((0,), (0,)), ((), ())),
                                   preferred_element_type=jnp.float32)
-    state_sc[...] = state * jnp.exp(cum[-1]) + s_local
+    state_sc[...] = state * jnp.exp(total) + s_local
     y_ref[0, 0] = y.astype(y_ref.dtype)
 
 
@@ -76,20 +85,24 @@ def ssd_scan_kernel(xh, dt, dA, Bm, Cm, *, interpret: bool = False):
     BH, C, Q, P = xh.shape
     N = Bm.shape[-1]
     kernel = functools.partial(_kernel, chunk=Q)
+    # dt/dA travel as [BH, C, 1, Q]: a (1, Q) row block is tile-legal
+    # (its sublane dim equals the array's), a (1, 1, Q) block is not
+    dt = dt.reshape(BH, C, 1, Q)
+    dA = dA.reshape(BH, C, 1, Q)
     return pl.pallas_call(
         kernel,
         grid=(BH, C),
         in_specs=[
             pl.BlockSpec((1, 1, Q, P), lambda b, c: (b, c, 0, 0)),
-            pl.BlockSpec((1, 1, Q), lambda b, c: (b, c, 0)),
-            pl.BlockSpec((1, 1, Q), lambda b, c: (b, c, 0)),
+            pl.BlockSpec((1, 1, 1, Q), lambda b, c: (b, c, 0, 0)),
+            pl.BlockSpec((1, 1, 1, Q), lambda b, c: (b, c, 0, 0)),
             pl.BlockSpec((1, 1, Q, N), lambda b, c: (b, c, 0, 0)),
             pl.BlockSpec((1, 1, Q, N), lambda b, c: (b, c, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, Q, P), lambda b, c: (b, c, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((BH, C, Q, P), xh.dtype),
         scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(xh, dt, dA, Bm, Cm)

@@ -29,18 +29,13 @@ import jax
 import numpy as np
 
 from ..configs.base import ShapeConfig
-from ..configs.registry import get_arch, tiny
+from ..configs.registry import parse_arch
 from ..data.pipeline import TokenPipeline
 from ..models.model import build_model
 from ..persistence.checkpoint import CheckpointManager
 from ..training.optimizer import make_optimizer
 from ..training.train_loop import make_train_step
-
-
-def parse_arch(spec: str):
-    if spec.startswith("tiny:"):
-        return tiny(get_arch(spec[5:]))
-    return get_arch(spec)
+from .compile_cache import enable_compile_cache
 
 
 def run_training(*, arch: str, steps: int, ckpt_dir: str,
@@ -127,6 +122,7 @@ def main() -> None:
                     choices=["nvtraverse", "izraelevitz"])
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
     out = run_training(arch=args.arch, steps=args.steps,
                        ckpt_dir=args.ckpt_dir, ckpt_every=args.ckpt_every,
                        global_batch=args.global_batch,

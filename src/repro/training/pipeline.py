@@ -21,7 +21,6 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 
 def mlp_block(p, x):
@@ -93,10 +92,10 @@ def make_gpipe_fn(mesh: Mesh, *, n_stages: int, axis: str = "stage"):
         else P()
 
     @functools.partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(jax.tree.map(lambda _: pspec, {"w1": 0, "w2": 0, "w3": 0}),
                   xspec),
-        out_specs=xspec, check_rep=False)
+        out_specs=xspec, check_vma=False)
     def fn(params, x_mb):
         params = jax.tree.map(lambda a: a[0], params)  # my stage's group
         return gpipe_forward(params, x_mb, n_stages=n_stages, axis=axis)

@@ -1,0 +1,12 @@
+"""Least time of the traced rounds' updates (their algorithmic bytes at
+the HBM peak, see bench/counts.py) over the device time of the update
+programs summed over the chips, in %."""
+from bench.readers import UPDATE, map_round_bytes, share
+
+
+def read(run):
+    if run.trace is None:
+        return None
+    sec, _ = run.trace.program_seconds(UPDATE.search)
+    least = map_round_bytes(run, "update") / run.peaks["hbm_bytes_per_s"]
+    return share(least, sec)

@@ -449,7 +449,8 @@ class RequestLog:
         result is dropped from the committed cache and a later request
         with that rid is served afresh."""
         with self.tracer.span("commit", n_results=len(results),
-                              n_evict=len(evict)):
+                              n_evict=len(evict),
+                              rids=[int(k) for k in results]):
             rel = self._claim_slot()
             rec = {int(k): list(v) for k, v in results.items()}
             evict = sorted({int(r) for r in evict})
@@ -463,7 +464,8 @@ class RequestLog:
                 self.io.fence()
             self._folded.add(rel)
             m0, r0 = self._dedup.migrations, self._dedup.rebalances
-            self._apply_record(rec, evict)
+            with self.tracer.span("dedup_round", op="update"):
+                self._apply_record(rec, evict)
             if self.timeline is not None:
                 # annotate live-traffic dedup growth/re-splits only (a
                 # restart replay folds records through _apply_record
@@ -632,8 +634,9 @@ class ServeEngine:
         ``registry``/``timeline``/``obs`` select the NVTrace metrics
         registry, the event timeline for snapshot/truncate/growth
         annotations, and toggle span/listener instrumentation (see
-        :class:`RequestLog`); per-request serve latency lands in the
-        ``serve_request_us`` histogram either way."""
+        :class:`RequestLog`); per-request serve latency (from the
+        ``serve`` call's entry to the commit of the request's batch) lands
+        in the ``serve_request_us`` histogram either way."""
         self.model = model
         self.params = params
         self.max_len = max_len
@@ -654,24 +657,32 @@ class ServeEngine:
         self._decode = jax.jit(model.decode_step)
 
     def _greedy_batch(self, prompts: np.ndarray, n_new: int) -> np.ndarray:
+        """Prefill, then one decode per token.  Spans: ``prefill`` (inputs
+        to the device, the prefill and the first argmax dispatched);
+        per token ``token_sync`` (the host waits for the token and copies
+        it back) and ``dispatch`` (the next decode and its argmax)."""
         B, S = prompts.shape
-        batch = {"tokens": jnp.asarray(prompts)}
         cfg = self.model.cfg
-        if cfg.family == "vlm":
-            batch["vis"] = jnp.zeros((B, cfg.vis_tokens, cfg.d_model),
-                                     jnp.float32)
-        if cfg.family == "encdec":
-            batch["frames"] = jnp.zeros((B, cfg.enc_seq, cfg.d_model),
-                                        jnp.float32)
-        logits, caches = self._prefill(self.params, batch)
+        span = self.tracer.span
+        with span("prefill"):
+            batch = {"tokens": jnp.asarray(prompts)}
+            if cfg.family == "vlm":
+                batch["vis"] = jnp.zeros((B, cfg.vis_tokens, cfg.d_model),
+                                         jnp.float32)
+            if cfg.family == "encdec":
+                batch["frames"] = jnp.zeros((B, cfg.enc_seq, cfg.d_model),
+                                            jnp.float32)
+            logits, caches = self._prefill(self.params, batch)
+            tok = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
         out = []
-        tok = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
         prefix = cfg.vis_tokens if cfg.family == "vlm" else 0
         for i in range(n_new):
-            out.append(np.asarray(tok))
-            logits, caches = self._decode(self.params, tok, caches,
-                                          jnp.int32(S + prefix + i))
-            tok = jnp.argmax(logits[:, 0], axis=-1).astype(jnp.int32)
+            with span("token_sync"):
+                out.append(np.asarray(tok))
+            with span("dispatch"):
+                logits, caches = self._decode(self.params, tok, caches,
+                                              jnp.int32(S + prefix + i))
+                tok = jnp.argmax(logits[:, 0], axis=-1).astype(jnp.int32)
         return np.stack(out, axis=1)        # [B, n_new]
 
     def serve(self, requests: Dict[int, np.ndarray], n_new: int = 8,
@@ -684,59 +695,62 @@ class ServeEngine:
         other requests share its batch — right-padding mixed lengths
         instead would leak pad tokens into the shorter rows' attention.
         Already-committed rids are skipped (exactly-once) and answered
-        from the log."""
-        with self.tracer.span("route", n_requests=len(requests)):
-            self.log.refresh()  # pick up other engine instances' commits
-            rids = sorted(requests)
-            todo = [rid for rid, done
-                    in zip(rids, self.log.is_committed(rids)) if not done]
-            groups: Dict[int, List[int]] = {}
-            for rid in todo:
-                groups.setdefault(int(requests[rid].shape[0]), []).append(rid)
-        self.metrics.counter("serving_requests_total").inc(len(rids))
-        self.metrics.counter("serving_dedup_hits_total").inc(
-            len(rids) - len(todo))
-        lat_hist = self.metrics.histogram("serve_request_us",
-                                          lo=1.0, hi=1e8, growth=1.25)
-        crashed = False
-        batches = 0
-        for length in sorted(groups):
-            for i in range(0, len(groups[length]), self.batch):
-                t_batch = time.perf_counter_ns()
-                batch_rids = groups[length][i:i + self.batch]
-                with self.tracer.span("plan", n=len(batch_rids),
-                                      prompt_len=length):
-                    prompts = _stack_batch(
-                        [requests[r] for r in batch_rids])
-                    gen = self._greedy_batch(prompts, n_new)  # traversal
-                # never evict a rid this call is serving: its result was
-                # just paid for and belongs in this call's return value
-                expired = ([r for r in self.log.expired_rids(self.retain)
-                            if r not in requests]
-                           if self.retain is not None else ())
-                self.log.commit({int(r): gen[j].tolist()  # the destination
-                                 for j, r in enumerate(batch_rids)},
-                                evict=expired)
-                self._commits_since_snap += 1
-                # every request in a (synchronous) batch experiences the
-                # batch's wall time — that is its serve latency
-                dur_us = (time.perf_counter_ns() - t_batch) / 1e3
-                for _ in batch_rids:
-                    lat_hist.record(dur_us)
-                self.metrics.counter("serving_batches_total").inc()
-                if self.snapshot_every is not None and \
-                        self._commits_since_snap >= self.snapshot_every:
-                    self.log.snapshot()
-                    self._commits_since_snap = 0
-                batches += 1
-                if crash_after_batches is not None and \
-                        batches >= crash_after_batches:
-                    self.log.io.crash(evict="none")
-                    crashed = True
+        from the log.  A rid's ``serve_request_us`` runs from this call's
+        entry to the end of its batch's commit."""
+        t_serve = time.perf_counter_ns()
+        span = self.tracer.span
+        with span("serve"):
+            with span("route", n_requests=len(requests)):
+                self.log.refresh()  # pick up other engine instances' commits
+                rids = sorted(requests)
+                with span("dedup_round", op="lookup"):
+                    done = self.log.is_committed(rids)
+                todo = [rid for rid, d in zip(rids, done) if not d]
+                groups: Dict[int, List[int]] = {}
+                for rid in todo:
+                    groups.setdefault(int(requests[rid].shape[0]),
+                                      []).append(rid)
+            self.metrics.counter("serving_requests_total").inc(len(rids))
+            self.metrics.counter("serving_dedup_hits_total").inc(
+                len(rids) - len(todo))
+            lat_hist = self.metrics.histogram("serve_request_us",
+                                              lo=1.0, hi=1e8, growth=1.25)
+            crashed = False
+            batches = 0
+            for length in sorted(groups):
+                for i in range(0, len(groups[length]), self.batch):
+                    batch_rids = groups[length][i:i + self.batch]
+                    with span("plan", n=len(batch_rids), prompt_len=length,
+                              rids=batch_rids):
+                        prompts = _stack_batch(
+                            [requests[r] for r in batch_rids])
+                        gen = self._greedy_batch(prompts, n_new)  # traversal
+                    # never evict a rid this call is serving: its result
+                    # was just paid for and belongs in this call's return
+                    expired = ([r for r in self.log.expired_rids(self.retain)
+                                if r not in requests]
+                               if self.retain is not None else ())
+                    self.log.commit({int(r): gen[j].tolist()  # destination
+                                     for j, r in enumerate(batch_rids)},
+                                    evict=expired)
+                    dur_us = (time.perf_counter_ns() - t_serve) / 1e3
+                    for _ in batch_rids:
+                        lat_hist.record(dur_us)
+                    self._commits_since_snap += 1
+                    self.metrics.counter("serving_batches_total").inc()
+                    if self.snapshot_every is not None and \
+                            self._commits_since_snap >= self.snapshot_every:
+                        self.log.snapshot()
+                        self._commits_since_snap = 0
+                    batches += 1
+                    if crash_after_batches is not None and \
+                            batches >= crash_after_batches:
+                        self.log.io.crash(evict="none")
+                        crashed = True
+                        break
+                if crashed:
                     break
-            if crashed:
-                break
-        committed = self.log.committed()
+            committed = self.log.committed()
         return {rid: committed[rid] for rid in requests if rid in committed}
 
     def took_effect(self, rids: Sequence[int]) -> np.ndarray:

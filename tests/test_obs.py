@@ -8,7 +8,9 @@ associativity — the property that makes cross-shard snapshot merging
 order-independent.  The span tests exercise the innermost-span charging
 rule against a real ``StagedIO`` instruction stream and cross-validate
 the listener's totals against a ``PersistTrace`` on the same stream via
-``FaultsTee``.
+``FaultsTee``.  The serving tests pin where the engine's spans sit, how
+they link, and that their profiler annotations share the profile's
+clock.
 """
 import json
 import math
@@ -224,6 +226,8 @@ def test_innermost_span_gets_the_instruction_bill(tmp_path):
     assert [r["span"] for r in recs] == ["plan", "flush_fence", "commit"]
     assert recs[0]["counts"] == {} and recs[0]["dur_us"] >= 0
     assert [r["depth"] for r in recs] == [0, 1, 0]
+    assert [r["parent"] for r in recs] == [None, commit.id, None]
+    assert len({r["id"] for r in recs}) == 3
     assert tr.totals == {"write": 1, "flush": 1, "fence": 1, "publish": 1}
     assert tr.span_counts == tr.totals           # every event was in-span
     assert reg.counter("persist_events_total", kind="fence").value == 1
@@ -280,6 +284,158 @@ def test_faults_tee_cross_validates_listener_against_trace(tmp_path):
     assert tr.totals == by_kind and tr.span_counts == by_kind
     # the trace side kept its CrashPlan site numbering too
     assert [s.kind for s in trace.sites].count("publish") == 5
+
+
+# --------------------------------------------------------------------- #
+# the serving engine's spans                                             #
+# --------------------------------------------------------------------- #
+N_NEW = 3
+TRAVERSAL = ("prefill", "token_sync", "dispatch")
+
+
+@pytest.fixture(scope="module")
+def served(tmp_path_factory):
+    """A tiny engine that served one call of three batches: rids 0-1 and
+    2 at prompt length 8, rids 3-4 at 12.  Returns the engine, the
+    requests, its registry and the span records of that call."""
+    import jax
+
+    from repro.configs.registry import get_arch, tiny
+    from repro.models.model import build_model
+    from repro.serving.engine import ServeEngine
+    cfg = tiny(get_arch("qwen3-1.7b"))
+    model = build_model(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    reg = MetricsRegistry()
+    eng = ServeEngine(model, params, max_len=32, batch_size=2,
+                      log_dir=tmp_path_factory.mktemp("log"), registry=reg)
+    rng = np.random.default_rng(0)
+    reqs = {rid: rng.integers(0, cfg.vocab, size=S).astype(np.int32)
+            for rid, S in enumerate((8, 8, 8, 12, 12))}
+    eng.serve(reqs, n_new=N_NEW)
+    return eng, reqs, reg, eng.tracer.records()
+
+
+def _children(recs):
+    kids = {}
+    for r in recs:
+        kids.setdefault(r["parent"], []).append(r)
+    return kids
+
+
+def _root_phases(recs):
+    kids = _children(recs)
+    (root,) = [r for r in recs if r["span"] == "serve"]
+    return root, kids, {name: [r for r in kids[root["id"]]
+                               if r["span"] == name]
+                        for name in ("route", "plan", "commit")}
+
+
+def test_serve_spans_nest_where_the_work_happens(served):
+    _, _, _, recs = served
+    root, kids, ph = _root_phases(recs)
+    assert root["parent"] is None and root["depth"] == 0
+    assert sorted(r["span"] for r in kids[root["id"]]) == \
+        ["commit"] * 3 + ["plan"] * 3 + ["route"]
+    (route,) = ph["route"]
+    assert [(r["span"], r["meta"]) for r in kids[route["id"]]] == \
+        [("dedup_round", {"op": "lookup"})]
+    for p in ph["plan"]:
+        assert [r["span"] for r in kids[p["id"]]] == \
+            ["prefill"] + ["token_sync", "dispatch"] * N_NEW
+    for c in ph["commit"]:
+        inner = {r["span"]: r for r in kids[c["id"]]}
+        assert len(kids[c["id"]]) == 2 and set(inner) == {"dedup_round",
+                                                          "flush_fence"}
+        assert inner["dedup_round"]["meta"] == {"op": "update"}
+
+
+def test_plan_and_commit_carry_the_batch_rids(served):
+    _, _, _, recs = served
+    _, _, ph = _root_phases(recs)
+    batches = [[0, 1], [2], [3, 4]]
+    assert [p["meta"]["rids"] for p in ph["plan"]] == batches
+    assert [p["meta"]["n"] for p in ph["plan"]] == [2, 1, 2]
+    assert [c["meta"]["rids"] for c in ph["commit"]] == batches
+
+
+def test_traversal_spans_persist_nothing(served):
+    """The paper's asymmetry inside the decode loop: the traversal's
+    spans charge no persistence instruction, the commit pays."""
+    _, _, _, recs = served
+    trav = [r for r in recs if r["span"] in TRAVERSAL]
+    assert len(trav) == 3 * (1 + 2 * N_NEW)
+    assert all(r["counts"] == {} for r in trav)
+    assert all(r["counts"].get("write") == 1
+               for r in recs if r["span"] == "commit")
+
+
+def test_every_parent_is_an_enclosing_span(served):
+    _, _, _, recs = served
+    by_id = {r["id"]: r for r in recs}
+    assert len(by_id) == len(recs)
+    for r in recs:
+        if r["parent"] is None:
+            assert r["depth"] == 0
+            continue
+        p = by_id[r["parent"]]
+        assert p["depth"] == r["depth"] - 1
+        assert p["t_us"] <= r["t_us"]
+        assert (r["t_us"] + r["dur_us"]
+                <= p["t_us"] + p["dur_us"] + 1e-6)
+
+
+def test_request_latency_runs_from_the_call_entry(served):
+    """A rid of a later batch waits for the batches before it: its
+    latency covers every plan and commit up to its own."""
+    _, reqs, reg, recs = served
+    _, _, ph = _root_phases(recs)
+    h = reg.histogram("serve_request_us", lo=1.0, hi=1e8, growth=1.25)
+    assert h.count == len(reqs)
+    work = [p["dur_us"] + c["dur_us"]
+            for p, c in zip(ph["plan"], ph["commit"])]
+    assert h.min >= work[0]
+    assert h.max >= sum(work)
+
+
+def test_span_annotations_share_the_profile_clock(served, tmp_path):
+    """Every span is also a profiler annotation of its name; moved by the
+    offset of a ``traced_window`` annotation opened as the benchmark
+    opens it, each ring record starts within 200 us of its annotation."""
+    import glob
+    import time
+
+    import jax
+    eng, reqs, _, _ = served
+    jax.profiler.start_trace(str(tmp_path))
+    window = jax.profiler.TraceAnnotation("traced_window")
+    window.__enter__()
+    start_ns = time.perf_counter_ns()
+    try:
+        eng.serve({rid + 100: p for rid, p in reqs.items()}, n_new=N_NEW)
+    finally:
+        stop_ns = time.perf_counter_ns()
+        window.__exit__(None, None, None)
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                        recursive=True)
+    host = {}
+    for p in jax.profiler.ProfileData.from_file(path).planes:
+        if not p.name.startswith("/device"):
+            for line in p.lines:
+                for e in line.events:
+                    host.setdefault(e.name, []).append(float(e.start_ns))
+    (win,) = host["traced_window"]
+    off = win - start_ns
+    tr = eng.tracer
+    recs = [r for r in tr.records()
+            if start_ns <= tr.epoch_ns + r["t_us"] * 1e3 <= stop_ns]
+    assert {r["span"] for r in recs} == {
+        "serve", "route", "dedup_round", "plan", "commit", "flush_fence",
+        *TRAVERSAL}
+    gaps = [min(abs(s - (tr.epoch_ns + r["t_us"] * 1e3 + off))
+                for s in host[r["span"]]) for r in recs]
+    assert max(gaps) < 200e3
 
 
 # --------------------------------------------------------------------- #

@@ -125,6 +125,37 @@ def attention_scores(q: jax.Array, k: jax.Array, v: jax.Array,
     return out
 
 
+def attention_decode(q: jax.Array, ck: jax.Array, cv: jax.Array,
+                     mask: jax.Array) -> jax.Array:
+    """One query token against the K/V cache, grouped-query.
+
+    q: [B,1,H,dh]; ck/cv: [B,S,K,dh] as stored; mask broadcastable to
+    [B,K,G,1,S] (True = attend), G = H // K.  Returns [B,1,H,dh].
+
+    The same products, mask and float32 softmax as `attention_scores`,
+    but the H query heads are split into K groups of G and contracted
+    against the cache as it is stored: the cache is read once, never
+    repeated up to H heads (a copy G times the cache's size per layer
+    per step).  On a mesh the K axis is constrained over "model".
+    """
+    from ..sharding.constraints import batch_axes, constrain
+    B, Sq, H, dh = q.shape
+    K = ck.shape[2]
+    ba = batch_axes()
+    qg = constrain(q.reshape(B, Sq, K, H // K, dh),
+                   ba, None, "model", None, None)
+    ck = constrain(ck, ba, None, "model", None)
+    cv = constrain(cv, ba, None, "model", None)
+    scores = jnp.einsum("bqkgd,bskd->bkgqs", qg, ck) / jnp.sqrt(dh).astype(
+        q.dtype)
+    scores = scores.astype(jnp.float32)
+    scores = jnp.where(mask, scores, NEG_INF)
+    scores = constrain(scores, ba, "model", None, None, None)
+    w = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+    out = jnp.einsum("bkgqs,bskd->bqkgd", w, cv)
+    return out.reshape(B, Sq, H, dh)
+
+
 def attention_blocked(q, k, v, *, causal: bool, window, chunk: int = 1024):
     """Online-softmax attention, scanned over KV chunks (XLA flash).
 
@@ -216,8 +247,7 @@ def self_attention(p: dict, x: jax.Array, cfg, *, positions,
         if window is not None:
             w_active = jnp.asarray(window)
             m = m & jnp.where(w_active > 0, kpos > cache_pos - w_active, True)
-        mask = m[None, None, None, :]
-        out = attention_scores(q, ck, cv, mask)
+        out = attention_decode(q, ck, cv, m[None, None, None, None, :])
         new_cache = {"k": ck, "v": cv}
     elif mode == "bidir":
         out = attention_scores(q, k, v, None)

@@ -126,17 +126,21 @@ def attention_scores(q: jax.Array, k: jax.Array, v: jax.Array,
 
 
 def attention_decode(q: jax.Array, ck: jax.Array, cv: jax.Array,
-                     mask: jax.Array) -> jax.Array:
-    """One query token against the K/V cache, grouped-query.
+                     mask: jax.Array, k: jax.Array, v: jax.Array) -> jax.Array:
+    """One query token against the K/V cache and its own K/V, grouped-query.
 
-    q: [B,1,H,dh]; ck/cv: [B,S,K,dh] as stored; mask broadcastable to
-    [B,K,G,1,S] (True = attend), G = H // K.  Returns [B,1,H,dh].
+    q: [B,1,H,dh]; ck/cv: [B,S,K,dh] as stored, read and never written;
+    mask broadcastable to [B,K,G,1,S] (True = attend), G = H // K; k/v:
+    [B,1,K,dh], the new token's own, always attended.  Returns [B,1,H,dh].
 
-    The same products, mask and float32 softmax as `attention_scores`,
-    but the H query heads are split into K groups of G and contracted
-    against the cache as it is stored: the cache is read once, never
-    repeated up to H heads (a copy G times the cache's size per layer
-    per step).  On a mesh the K axis is constrained over "model".
+    The same products, mask and float32 softmax as `attention_scores`
+    over the cache with the new token written at its position, but the
+    H query heads are split into K groups of G and contracted against
+    the cache as it is stored: the cache is read once, never repeated up
+    to H heads nor copied to hold the new token.  The softmax runs over
+    the cache's scores and the new token's together; both value sums
+    accumulate in float32 and are rounded once.  On a mesh the K axis is
+    constrained over "model".
     """
     from ..sharding.constraints import batch_axes, constrain
     B, Sq, H, dh = q.shape
@@ -146,14 +150,20 @@ def attention_decode(q: jax.Array, ck: jax.Array, cv: jax.Array,
                    ba, None, "model", None, None)
     ck = constrain(ck, ba, None, "model", None)
     cv = constrain(cv, ba, None, "model", None)
-    scores = jnp.einsum("bqkgd,bskd->bkgqs", qg, ck) / jnp.sqrt(dh).astype(
-        q.dtype)
-    scores = scores.astype(jnp.float32)
-    scores = jnp.where(mask, scores, NEG_INF)
+    scale = jnp.sqrt(dh).astype(q.dtype)
+    s_cache = jnp.einsum("bqkgd,bskd->bkgqs", qg, ck) / scale
+    s_cache = jnp.where(mask, s_cache.astype(jnp.float32), NEG_INF)
+    s_new = (jnp.einsum("bqkgd,bskd->bkgqs", qg, k) / scale).astype(
+        jnp.float32)
+    scores = jnp.concatenate([s_cache, s_new], axis=-1)
     scores = constrain(scores, ba, "model", None, None, None)
     w = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
-    out = jnp.einsum("bkgqs,bskd->bqkgd", w, cv)
-    return out.reshape(B, Sq, H, dh)
+    S = ck.shape[1]
+    out = (jnp.einsum("bkgqs,bskd->bqkgd", w[..., :S], cv,
+                      preferred_element_type=jnp.float32)
+           + jnp.einsum("bkgqs,bskd->bqkgd", w[..., S:], v,
+                        preferred_element_type=jnp.float32))
+    return out.astype(q.dtype).reshape(B, Sq, H, dh)
 
 
 def attention_blocked(q, k, v, *, causal: bool, window, chunk: int = 1024):
@@ -229,26 +239,27 @@ def self_attention(p: dict, x: jax.Array, cfg, *, positions,
     ``window`` may be a traced scalar (0 = full attention) so that the
     gemma3 local/global pattern compiles as ONE scanned block.
 
-    ``cache`` (a {'k','v'} buffer of length S_max) is consumed+updated in
-    decode mode; in causal mode a provided cache buffer is *filled* from
-    position 0 (prefill) and the attention itself runs over the current
-    tokens only.
+    ``cache`` (a {'k','v'} buffer of length S_max) is only read in decode
+    mode: positions below ``cache_pos`` are attended together with the
+    new token, whose own {'k','v'} ([B,1,K,dh]) is returned for the stack
+    to write at ``cache_pos`` (`transformer.write_kv`).  In causal
+    mode a provided cache buffer is *filled* from position 0 (prefill)
+    and the attention itself runs over the current tokens only.
     """
     B, S, _ = x.shape
     q, k, v = qkv(p, x, cfg, positions)
     if mode == "decode":
-        # one new token (S == 1) against the persistent cache
+        # one new token (S == 1) against the persistent cache, which is
+        # read as given: the caller writes the returned K/V at cache_pos
         assert cache is not None
-        ck = jax.lax.dynamic_update_slice_in_dim(cache["k"], k, cache_pos, 1)
-        cv = jax.lax.dynamic_update_slice_in_dim(cache["v"], v, cache_pos, 1)
-        Sk = ck.shape[1]
-        kpos = jnp.arange(Sk)
-        m = kpos <= cache_pos
+        kpos = jnp.arange(cache["k"].shape[1])
+        m = kpos < cache_pos
         if window is not None:
             w_active = jnp.asarray(window)
             m = m & jnp.where(w_active > 0, kpos > cache_pos - w_active, True)
-        out = attention_decode(q, ck, cv, m[None, None, None, None, :])
-        new_cache = {"k": ck, "v": cv}
+        out = attention_decode(q, cache["k"], cache["v"],
+                               m[None, None, None, None, :], k, v)
+        new_cache = {"k": k, "v": v}
     elif mode == "bidir":
         out = attention_scores(q, k, v, None)
         new_cache = None

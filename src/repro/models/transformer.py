@@ -163,6 +163,27 @@ def _layer_window(cfg, idx):
     return jnp.where(is_global, 0, cfg.local_window)
 
 
+def write_kv(caches, new, *, mode, cache_pos, layer=None):
+    """Fold the attention caches a stack's layers returned into the
+    stacked {'k','v'} caches ([L,B,S,K,dh]) they read.
+
+    In decode each layer returns only the new token's K/V ([B,1,K,dh],
+    [L,B,1,K,dh] as a scan's output) and that one position is written at
+    ``cache_pos``: in place when the caches are donated, and no layer's
+    cache is copied.  Otherwise ``new`` is a filled cache that replaces
+    the stack's whole, or layer ``layer``'s.
+    """
+    if layer is None and mode != "decode":
+        return new
+    if layer is not None:
+        new = jax.tree.map(lambda a: a[None], new)
+    start = (0 if layer is None else layer, 0,
+             cache_pos if mode == "decode" else 0, 0, 0)
+    return jax.tree.map(
+        lambda buf, upd: jax.lax.dynamic_update_slice(buf, upd, start),
+        caches, new)
+
+
 def dense_stack(params, x, cfg, *, positions, mode, caches=None,
                 cache_pos=None):
     """params: stacked [L, ...]; caches: stacked {'k','v'} or None."""
@@ -180,7 +201,7 @@ def dense_stack(params, x, cfg, *, positions, mode, caches=None,
     body = _maybe_remat(body, cfg)
     xs = (params, jnp.arange(L), caches)
     x, new_caches = _scan(cfg, body, x, xs, L)
-    return x, new_caches
+    return x, write_kv(caches, new_caches, mode=mode, cache_pos=cache_pos)
 
 
 def moe_stack(params, x, cfg, *, positions, mode, caches=None,
@@ -198,6 +219,7 @@ def moe_stack(params, x, cfg, *, positions, mode, caches=None,
     body = _maybe_remat(body, cfg)
     (x, aux), new_caches = _scan(
         cfg, body, (x, jnp.float32(0.0)), (params, caches), L)
+    new_caches = write_kv(caches, new_caches, mode=mode, cache_pos=cache_pos)
     return x, new_caches, aux / L
 
 
@@ -221,9 +243,9 @@ def hybrid_stack(params, x, cfg, *, positions, mode, caches=None,
 
     ``params = {"mamba": stacked[L], "shared": dense_block_params}``;
     ``caches = {"ssm": stacked[L] SSMCache, "attn": {'k','v'} [n_inv, ...]}``.
-    The shared block's caches are carried (updated via dynamic slicing at
-    the invocation index) because its parameters are tied across
-    invocations but its KV history is not.
+    The shared block's caches are carried (written at the invocation index
+    by `write_kv`, one position in decode) because its parameters are tied
+    across invocations but its KV history is not.
     """
     L, k = cfg.n_layers, cfg.shared_attn_every
     shared = params["shared"]
@@ -249,10 +271,8 @@ def hybrid_stack(params, x, cfg, *, positions, mode, caches=None,
             y, new_cache = dense_block(shared, x, cfg, positions=positions,
                                        mode=mode, cache=cache,
                                        cache_pos=cache_pos)
-            attn_caches = jax.tree.map(
-                lambda buf, upd: jax.lax.dynamic_update_index_in_dim(
-                    buf, upd, inv, 0),
-                attn_caches, new_cache)
+            attn_caches = write_kv(attn_caches, new_cache, mode=mode,
+                                   cache_pos=cache_pos, layer=inv)
             return y, attn_caches
 
         is_shared = (idx % k) == (k - 1)
@@ -292,19 +312,22 @@ def decoder_stack(params, x, cfg, *, positions, mode, enc_out=None,
 
     During train/prefill ``enc_out`` is given and per-layer cross KV is
     computed in-scan; during decode the precomputed ``xa_caches`` [L,...]
-    are consumed.
+    are read, and returned as given.
     """
+    decode = mode == "decode"
+
     def body(x, inp):
         lp, cache, xa_cache = inp
         y, new_cache, xa_kv = encdec_block(
             lp, x, cfg, positions=positions, mode=mode, cache=cache,
             cache_pos=cache_pos, enc_out=enc_out, xa_cache=xa_cache)
-        return y, (new_cache, xa_kv)
+        return y, (new_cache, None if decode else xa_kv)
 
     body = _maybe_remat(body, cfg)
     x, (new_caches, xa_kvs) = _scan(
         cfg, body, x, (params, caches, xa_caches), cfg.n_layers)
-    return x, new_caches, xa_kvs
+    new_caches = write_kv(caches, new_caches, mode=mode, cache_pos=cache_pos)
+    return x, new_caches, xa_caches if decode else xa_kvs
 
 
 def precompute_cross_caches(params, enc_out, cfg):

@@ -654,7 +654,9 @@ class ServeEngine:
         self.timeline = self.log.timeline
         self._prefill = jax.jit(
             lambda p, b: model.prefill(p, b, max_len))
-        self._decode = jax.jit(model.decode_step)
+        # the caches are donated: the step writes one position of each
+        # layer's K/V into them in place, and the caller drops the input
+        self._decode = jax.jit(model.decode_step, donate_argnums=(2,))
 
     def _greedy_batch(self, prompts: np.ndarray, n_new: int) -> np.ndarray:
         """Prefill, then one decode per token.  Spans: ``prefill`` (inputs
